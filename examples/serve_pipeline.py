@@ -10,7 +10,9 @@ Composes every layer of the batched serving story:
     per-intent prompt-prefix caching — sessions gated to the same intent
     reuse one cached prefill of the gated system prompt + catalog.
 
-  PYTHONPATH=src python examples/serve_pipeline.py [--requests 12]
+  PYTHONPATH=src python examples/serve_pipeline.py --smoke [--requests 12]
+
+Without ``--smoke`` the planner serves at its full published widths.
 """
 import argparse
 import os
@@ -22,7 +24,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import numpy as np
 
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.core.agent import Agent
 from repro.core.catalog import (build_catalog, catalog_intent_libraries,
                                 catalog_intent_map)
@@ -34,6 +36,7 @@ from repro.core.tools import DEFAULT_REGISTRY
 from repro.env.evaluator import evaluate_results
 from repro.env.tasks import make_benchmark
 from repro.env.world import build_world
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import count_params_analytic, init_params
 from repro.serving.cluster import EngineCluster, ROUTER_POLICIES
 from repro.serving.engine import InferenceEngine
@@ -41,8 +44,13 @@ from repro.serving.neural_planner import BatchedNeuralIntentClassifier
 from repro.serving.pipeline import GeckOptPipeline, PipelineConfig
 
 
-def main():
+def main(argv=None):
+    """Run the demo; returns the session count, wall time and the
+    pipeline's and engine's stats."""
     ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", default="planner-proxy-100m")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the architecture's small smoke variant")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--concurrency", type=int, default=8)
     ap.add_argument("--backend", default=None,
@@ -95,7 +103,7 @@ def main():
                     help="write the unified pipeline+engine trace here "
                          "(.jsonl = record-per-line, anything else = "
                          "Chrome trace-event JSON for Perfetto)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.spec_decode and args.draft_k < 1:
         ap.error(f"--spec-decode needs --draft-k >= 1, "
                  f"got {args.draft_k}")
@@ -107,8 +115,10 @@ def main():
     if args.retriever_k is not None and args.retriever_k < 1:
         ap.error(f"--retriever-k must be >= 1, got {args.retriever_k}")
 
+    enable_compile_cache()
     # --- the serving fleet: engine(s) + one batched gate model -----------
-    cfg = get_smoke_config("planner-proxy-100m")
+    cfg = (get_smoke_config(args.arch) if args.smoke
+           else get_config(args.arch))
     params = init_params(jax.random.PRNGKey(0), cfg)
     from repro.serving.specdec import SpecConfig
     spec = (SpecConfig(draft_cfg=cfg, draft_params=params,
@@ -226,6 +236,8 @@ def main():
         write_trace(tracer, args.trace_out)
         print(f"trace: {len(tracer.records)} records -> "
               f"{args.trace_out}")
+    return {"sessions": len(results), "wall_s": dt, "pipeline": ps,
+            "engine": es}
 
 
 if __name__ == "__main__":

@@ -33,7 +33,7 @@ from repro.analysis.findings import Finding, make_finding
 REQUIRED_BACKENDS = ("reference", "pallas")
 
 #: kernels/ modules that are not kernel implementations
-_NON_KERNEL_MODULES = {"__init__", "ref", "backend"}
+_NON_KERNEL_MODULES = {"__init__", "ref", "backend", "tiling"}
 
 
 def analyze_backend_registry(kernels_dir: Path) -> List[Finding]:

@@ -14,10 +14,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:                                     # jax >= 0.8 home of thread_resources
-    from jax._src.mesh import thread_resources as _tr
-except ImportError:                      # pragma: no cover - older jax
-    from jax.interpreters.pxla import thread_resources as _tr
+from jax._src.mesh import thread_resources as _tr
 
 
 def _mesh():

@@ -7,9 +7,10 @@ dispatches through a named :class:`KernelBackend`:
   * ``reference`` — the pure-jnp paths (layers.attention's chunked
     GSPMD-friendly attention, lax.top_k routing, associative-scan SSM,
     chunkwise mLSTM). Always available, partitionable under pjit.
-  * ``pallas``    — the hand-tiled Pallas TPU kernels in this package.
-    On CPU they run under ``interpret=True`` (bit-accurate, slow), so
-    the same selection is testable everywhere.
+  * ``pallas``    — the hand-tiled Pallas TPU kernels in this package,
+    compiled on a TPU. On CPU they run under ``interpret=True``
+    (bit-accurate, slow), so the same selection is testable there; any
+    other platform is refused.
 
 Selection, in precedence order:
 
@@ -232,7 +233,14 @@ register_backend(KernelBackend(
 # -------------------------------------------------------------- pallas ----
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode runs the kernels on the CPU, for tests; on a TPU
+    they always compile. Any other platform has no Pallas TPU path."""
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the pallas backend runs on 'tpu' (compiled) or 'cpu' "
+            f"(interpret mode), not on {platform!r}")
+    return platform == "cpu"
 
 
 def _pl_attention(q, k, v, *, causal=True, window=0, cap=0.0, scale=0.0,

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import compiler_params
+
 NEG_INF = -1e30
 
 
@@ -107,7 +109,7 @@ def flash_decode(q, k_cache, v_cache, kv_len, *, cap: float = 0.0,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(kv_len, qf, k_cache, v_cache)

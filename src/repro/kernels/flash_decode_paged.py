@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import compiler_params
+
 NEG_INF = -1e30
 
 
@@ -119,7 +121,7 @@ def flash_decode_paged(q, k_pages, v_pages, block_tab, kv_len, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tab, kv_len, qf, k_pages, v_pages)

@@ -11,6 +11,10 @@ block_q/block_k default to 128/256 — multiples of the 128-wide MXU tile,
 with the (block_q, block_k) score tile + accumulators well inside the
 ~16 MiB/core VMEM budget for hd ≤ 256.
 
+Any Sq and Sk compile: a sequence axis longer than its block is padded
+to a whole number of 8-aligned blocks (kernels/tiling.py). Padded key
+rows are masked; padded query rows are sliced off the output.
+
 GQA: the kv head index is derived from the q head index in the BlockSpec
 index maps (hq // group).
 
@@ -29,12 +33,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import compiler_params, fit_block, pad_axis
+
 NEG_INF = -1e30
 
 
 def _kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             causal: bool, window: int, cap: float, scale: float,
-            block_q: int, block_k: int, nk: int):
+            block_q: int, block_k: int, nk: int, kv_len: int):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     q_off = qoff_ref[0]
@@ -58,6 +64,8 @@ def _kernel(qoff_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32,
                                                    (block_q, block_k), 1)
     mask = jnp.ones_like(s, dtype=jnp.bool_)
+    if kv_len:                                         # padded key rows
+        mask = mask & (kpos < kv_len)
     if causal:
         mask = mask & (kpos <= qpos)
     if window:
@@ -92,23 +100,19 @@ def flash_prefill(q, k, v, *, causal: bool = True, window: int = 0,
     Hkv, Sk = k.shape[1], k.shape[2]
     G = Hq // Hkv
     scale = scale if scale else 1.0 / math.sqrt(hd)
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    while Sq % block_q:
-        block_q //= 2
-    while Sk % block_k:
-        block_k //= 2
-    assert Sq % block_q == 0 and Sk % block_k == 0
-    nq, nk = Sq // block_q, Sk // block_k
+    block_q, sq_pad = fit_block(Sq, block_q, 8)
+    block_k, sk_pad = fit_block(Sk, block_k, 8)
+    nq, nk = sq_pad // block_q, sk_pad // block_k
 
-    qf = q.reshape(B * Hq, Sq, hd)
-    kf = k.reshape(B * Hkv, Sk, hd)
-    vf = v.reshape(B * Hkv, Sk, hd)
+    qf = pad_axis(q.reshape(B * Hq, Sq, hd), 1, sq_pad)
+    kf = pad_axis(k.reshape(B * Hkv, Sk, hd), 1, sk_pad)
+    vf = pad_axis(v.reshape(B * Hkv, Sk, hd), 1, sk_pad)
     q_off = jnp.asarray(q_offset, jnp.int32).reshape((1,))
 
     kernel = functools.partial(
         _kernel, causal=causal, window=window, cap=cap, scale=scale,
-        block_q=block_q, block_k=block_k, nk=nk)
+        block_q=block_q, block_k=block_k, nk=nk,
+        kv_len=Sk if sk_pad != Sk else 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -132,9 +136,9 @@ def flash_prefill(q, k, v, *, causal: bool = True, window: int = 0,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * Hq, Sq, hd), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B * Hq, sq_pad, hd), q.dtype),
+        compiler_params=compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_off, qf, kf, vf)
-    return out.reshape(B, Hq, Sq, hd)
+    return out[:, :Sq].reshape(B, Hq, Sq, hd)

@@ -15,7 +15,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.tiling import compiler_params, fit_block, pad_axis
 
 NEG_INF = -1e30
 
@@ -48,11 +49,9 @@ def moe_router_topk(logits, k: int, *, block_t: int = 256,
                     interpret: bool = True):
     """logits: (T, E) -> (weights (T,k) fp32, idx (T,k) int32)."""
     T, E = logits.shape
-    block_t = min(block_t, T)
-    while T % block_t:
-        block_t //= 2
-    assert T % block_t == 0
-    nt = T // block_t
+    # pad rows route independently and are sliced off
+    block_t, t_pad = fit_block(T, block_t, 8)
+    nt = t_pad // block_t
 
     kernel = functools.partial(_kernel, k=k)
     w, i = pl.pallas_call(
@@ -61,10 +60,10 @@ def moe_router_topk(logits, k: int, *, block_t: int = 256,
         in_specs=[pl.BlockSpec((block_t, E), lambda t: (t, 0))],
         out_specs=[pl.BlockSpec((block_t, k), lambda t: (t, 0)),
                    pl.BlockSpec((block_t, k), lambda t: (t, 0))],
-        out_shape=[jax.ShapeDtypeStruct((T, k), jnp.float32),
-                   jax.ShapeDtypeStruct((T, k), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        out_shape=[jax.ShapeDtypeStruct((t_pad, k), jnp.float32),
+                   jax.ShapeDtypeStruct((t_pad, k), jnp.int32)],
+        compiler_params=compiler_params(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(logits)
-    return w, i
+    )(pad_axis(logits, 0, t_pad))
+    return w[:T], i[:T]

@@ -11,6 +11,12 @@ the roofline-optimal regime for SSMs).
 The scan starts from an explicit initial state ``h0`` and returns the
 final state alongside the outputs, so serving can continue a sequence
 (decode / chunked-prefill extend) through the same kernel.
+
+Any d_inner and S compile: d_inner is tiled in multiples of 128 lanes
+and S in multiples of 8 rows (kernels/tiling.py), zero-padded. A zero
+dt makes a pad step exactly the identity on the state (exp(0)=1 decay,
+zero input), so padded channels and trailing pad steps leave h_last
+untouched; their outputs are sliced off.
 """
 from __future__ import annotations
 
@@ -20,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.tiling import compiler_params, fit_block, pad_axis
 
 
 def _kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref, y_ref, hf_ref,
@@ -55,16 +63,15 @@ def ssm_scan(dt, x, B_, C_, A, h0=None, *, block_d: int = 256,
     state (B,di,n). Returns (y (B,S,di) fp32, h_last (B,di,n) fp32)."""
     Bsz, S, di = x.shape
     n = A.shape[-1]
-    block_d = min(block_d, di)
-    block_s = min(block_s, S)
-    while di % block_d:
-        block_d //= 2
-    while S % block_s:
-        block_s //= 2
-    assert di % block_d == 0 and S % block_s == 0
-    nd, ns = di // block_d, S // block_s
+    block_d, d_pad = fit_block(di, block_d, 128)
+    block_s, s_pad = fit_block(S, block_s, 8)
+    nd, ns = d_pad // block_d, s_pad // block_s
     if h0 is None:
         h0 = jnp.zeros((Bsz, di, n), jnp.float32)
+
+    def pad_seq(t, d=True):
+        t = pad_axis(t, 1, s_pad)
+        return pad_axis(t, 2, d_pad) if d else t
 
     kernel = functools.partial(_kernel, block_s=block_s, ns=ns)
     y, h_last = pl.pallas_call(
@@ -85,11 +92,13 @@ def ssm_scan(dt, x, B_, C_, A, h0=None, *, block_d: int = 256,
                          lambda b, d, s: (b, s, d)),
             pl.BlockSpec((None, block_d, n), lambda b, d, s: (b, d, 0)),
         ],
-        out_shape=[jax.ShapeDtypeStruct((Bsz, S, di), jnp.float32),
-                   jax.ShapeDtypeStruct((Bsz, di, n), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((Bsz, s_pad, d_pad), jnp.float32),
+                   jax.ShapeDtypeStruct((Bsz, d_pad, n), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(dt, x, B_, C_, A.astype(jnp.float32), h0.astype(jnp.float32))
-    return y, h_last
+    )(pad_seq(dt), pad_seq(x), pad_seq(B_, d=False), pad_seq(C_, d=False),
+      pad_axis(A.astype(jnp.float32), 0, d_pad),
+      pad_axis(h0.astype(jnp.float32), 1, d_pad))
+    return y[:, :S, :di], h_last[:, :di]

@@ -28,6 +28,7 @@ import time
 import jax
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import init_params
 from repro.obs import Tracer
 from repro.obs.export import write_trace
@@ -117,6 +118,10 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--arch", default="planner-proxy-100m")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--requests", type=int, default=16)
+    ap.add_argument("--prompt", action="append", default=None,
+                    help="single-engine mode: serve this prompt "
+                         "(repeatable; one request each) instead of "
+                         "--requests built-in demo prompts")
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--cache-len", type=int, default=512)
@@ -236,6 +241,9 @@ def validate_args(ap: argparse.ArgumentParser, args):
         ap.error("--catalog-size needs --retriever-k: the launcher has "
                  "no intent gate, so a scaled catalog is only servable "
                  "through retrieved toolsets")
+    if args.prompt and args.replicas > 1:
+        ap.error("--prompt applies to the single-engine path; cluster "
+                 "mode serves the synthetic intent workload")
     if args.retriever_k is not None and args.replicas > 1:
         ap.error("--retriever-k applies to the single-engine prompt "
                  "path; cluster mode serves the synthetic intent "
@@ -245,8 +253,12 @@ def validate_args(ap: argparse.ArgumentParser, args):
 
 
 def main(argv=None):
+    """Run the launcher. The single-engine path returns its throughput
+    stats plus ``requests``, ``finished``, ``wall_s`` and the generated
+    token ids per request (``outputs``, in submission order)."""
     ap = build_parser()
     args = validate_args(ap, ap.parse_args(argv))
+    enable_compile_cache()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))
@@ -263,7 +275,7 @@ def main(argv=None):
         serve_cluster(cfg, params, args, spec_decode=spec)
         return
 
-    prompts = [
+    prompts = args.prompt or [
         f"Plot xview1 images around Tampa Bay with cloud cover below "
         f"{10 + i}%" for i in range(args.requests)]
     exposures = None
@@ -355,6 +367,9 @@ def main(argv=None):
         write_trace(tracer, args.trace_out)
         print(f"trace: {len(tracer.records)} records -> "
               f"{args.trace_out}")
+    return {**st, "requests": len(prompts), "finished": len(done),
+            "wall_s": dt, "outputs": [r.output for r in sorted(
+                done, key=lambda r: r.request_id)]}
 
 
 if __name__ == "__main__":

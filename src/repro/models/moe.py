@@ -191,7 +191,6 @@ def _dispatch_shard_map(p, x, w, idx, mcfg, act):
     and no dispatch matmuls at all. Falls back to `gather` without a mesh.
     """
     from repro.distributed.annotate import _mesh
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = _mesh()
     if mesh is None or "model" not in mesh.axis_names:
@@ -236,12 +235,12 @@ def _dispatch_shard_map(p, x, w, idx, mcfg, act):
         return jax.lax.psum(y.astype(xb.dtype), "model")
 
     p_exp = {k: p[k] for k in ("w_gate", "w_up", "w_down")}
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P("model"), p_exp),
                   P(bspec, None, None), P(bspec, None, None),
                   P(bspec, None, None)),
-        out_specs=P(bspec, None, None), check_rep=False)
+        out_specs=P(bspec, None, None), check_vma=False)
     return fn(p_exp, x, w, idx)
 
 

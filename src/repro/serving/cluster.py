@@ -29,9 +29,12 @@ a ``serving/workload.py`` schedule through the cluster and collects
 TTFT / E2E / queue-wait percentiles, per-replica utilization and SLA
 attainment — no wall-clock anywhere, so runs are exactly reproducible.
 
-Replicas share one set of jitted step functions (same config, cache
-length and backend => identical traces), so an N-replica cluster
-compiles once, not N times. Outputs are bit-identical across routing
+Replica i's weights and KV caches are committed to device
+``devices[i % len(devices)]`` (default: every local device), so its
+jitted steps run there: on a four-chip host, four replicas hold one
+chip each. Replicas share one set of jitted step functions (same
+config, cache length and backend => identical traces), so an N-replica
+cluster traces once, not N times. Outputs are bit-identical across routing
 policies when requests carry sampler seeds: prefix-extend logits match
 full-prefill logits bitwise (tests/test_cluster.py proves parity).
 
@@ -43,11 +46,14 @@ unchanged — sessions get replica affinity by their intent prefix.
 """
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import heapq
 from collections import deque
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+import jax
 
 from repro.obs import MetricsRegistry, NULL_TRACER, percentile
 from repro.serving.engine import EngineSession, InferenceEngine, Request
@@ -324,6 +330,7 @@ class EngineCluster:
                  prefill_budget: Optional[int] = None,
                  interleave: Optional[bool] = None,
                  admission: Optional[str] = None,
+                 devices: Optional[Sequence[Any]] = None,
                  tracer=None, metrics: Optional[MetricsRegistry] = None):
         # one shared registry for the fleet: each replica publishes
         # through a replica=i facade, the cluster's own counters and
@@ -340,13 +347,13 @@ class EngineCluster:
                                            kv_mode, kv_blocks,
                                            block_size, spec_decode,
                                            prefill_budget, interleave,
-                                           admission)):
+                                           admission, devices)):
                 raise ValueError(
                     "engines= is mutually exclusive with cfg/params/"
                     "max_batch/cache_len/seed/backend/kv_mode/"
                     "kv_blocks/block_size/spec_decode/prefill_budget/"
-                    "interleave/admission (prebuilt replicas keep "
-                    "their own configuration)")
+                    "interleave/admission/devices (prebuilt replicas "
+                    "keep their own configuration)")
             self.replicas = list(engines)
             for i, e in enumerate(self.replicas):
                 # prebuilt replicas keep their own registries; the
@@ -361,14 +368,22 @@ class EngineCluster:
             cache_len = 512 if cache_len is None else cache_len
             seed = 0 if seed is None else seed
             kv_mode = "dense" if kv_mode is None else kv_mode
+            devices = list(devices or jax.local_devices())
             self.replicas = []
             for i in range(n_replicas):
-                e = InferenceEngine(cfg, params, max_batch=max_batch,
+                dev = devices[i % len(devices)]
+                spec_i = (None if spec_decode is None
+                          else dataclasses.replace(
+                              spec_decode,
+                              draft_params=jax.device_put(
+                                  spec_decode.draft_params, dev)))
+                e = InferenceEngine(cfg, jax.device_put(params, dev),
+                                    max_batch=max_batch,
                                     cache_len=cache_len, seed=seed + i,
                                     backend=backend, kv_mode=kv_mode,
                                     kv_blocks=kv_blocks,
                                     block_size=block_size,
-                                    spec_decode=spec_decode,
+                                    spec_decode=spec_i,
                                     prefill_budget=prefill_budget,
                                     interleave=(True if interleave
                                                 is None else interleave),
@@ -377,6 +392,9 @@ class EngineCluster:
                                     metrics=self.metrics.labeled(
                                         replica=i))
                 e.trace_group = i
+                e.cache = jax.device_put(e.cache, dev)
+                if e.spec is not None:
+                    e.spec.cache = jax.device_put(e.spec.cache, dev)
                 if self.replicas:
                     # identical (cfg, cache_len, backend) closures =>
                     # replicas share one jit cache: compile once, not N×

@@ -36,7 +36,7 @@ def bad_call(x, s):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((4, 8), x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=True,
     )(x)

@@ -34,7 +34,7 @@ def clean_call(x, kv_len):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((4, 128), x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=True,
     )(kv_len, x)

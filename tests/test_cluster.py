@@ -1,6 +1,10 @@
 """Multi-replica serving cluster: router policies, exact token parity
 across replica counts, affinity vs round-robin prefix-hit rates, and
 pipeline-on-cluster integration."""
+import os
+import subprocess
+import sys
+
 import jax
 import numpy as np
 import pytest
@@ -105,6 +109,57 @@ def test_prebuilt_engines_reject_sizing_kwargs(pool):
         EngineCluster(engines=pool, max_batch=16)
     with pytest.raises(ValueError, match="mutually exclusive"):
         EngineCluster(engines=pool, cache_len=1024)
+    with pytest.raises(ValueError, match="mutually exclusive"):
+        EngineCluster(engines=pool, devices=jax.devices())
+
+
+def test_replicas_committed_to_their_devices(pool):
+    """Replica i's weights and caches sit on devices[i % len(devices)]
+    (one device here: all of them on it)."""
+    dev = jax.devices()[0]
+    for e in pool:
+        for tree in (e.params, e.cache):
+            assert {d for leaf in jax.tree.leaves(tree)
+                    for d in leaf.devices()} == {dev}
+
+
+PLACEMENT_SCRIPT = r"""
+import jax
+from repro.configs import get_smoke_config
+from repro.models.model import init_params
+from repro.serving.cluster import EngineCluster
+from repro.serving.workload import (WorkloadConfig, make_workload,
+                                    register_workload_prefixes, uniform_mix)
+cfg = get_smoke_config("planner-proxy-100m")
+params = init_params(jax.random.PRNGKey(0), cfg)
+reqs = make_workload(WorkloadConfig(n_sessions=6, seed=1, max_turns=2,
+                                    intent_mix=uniform_mix(),
+                                    max_new_tokens=3, temperature=0.8))
+outs = []
+for devs in (jax.devices(), jax.devices()[:1]):
+    cl = EngineCluster(cfg, params, 4, router="intent_affinity",
+                       max_batch=2, cache_len=192, devices=devs)
+    for i, e in enumerate(cl.replicas):
+        for tree in (e.params, e.cache):
+            assert {d for l in jax.tree.leaves(tree) for d in l.devices()}                 == {devs[i % len(devs)]}
+    register_workload_prefixes(cl, reqs)
+    outs.append(cl.run_workload(reqs).outputs())
+assert len(outs[0]) == len(reqs) and outs[0] == outs[1]
+print("PLACEMENT_OK")
+"""
+
+
+def test_four_device_placement_keeps_tokens():
+    """On four devices each replica runs on its own; the tokens equal
+    those of the same seeded workload with all replicas on one device.
+    Runs in a subprocess: the device count locks at JAX start-up."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    out = subprocess.run([sys.executable, "-c", PLACEMENT_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert "PLACEMENT_OK" in out.stdout, (out.stdout[-2000:],
+                                          out.stderr[-2000:])
 
 
 # ------------------------------------------------- exact token parity ------

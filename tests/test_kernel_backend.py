@@ -51,6 +51,19 @@ def test_backend_registry_resolution():
         KB.get_backend("cuda")
 
 
+@pytest.mark.parametrize("platform,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, platform, interpret):
+    """Kernels compile on a TPU, interpret on the CPU, and refuse any
+    other platform instead of quietly interpreting there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="not on 'gpu'"):
+            KB._interpret()
+    else:
+        assert KB._interpret() is interpret
+
+
 # ------------------------------------- serving-shape kernel-vs-ref sweep ----
 
 def test_kernel_vs_ref_at_serving_shapes():
