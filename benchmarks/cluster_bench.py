@@ -274,8 +274,8 @@ def bench_trace(n_replicas: int = 2, n_sessions: int = 8, seed: int = 0,
     from repro.configs import get_smoke_config
     from repro.models.model import init_params
     from repro.obs import NULL_TRACER, Tracer
-    from repro.obs.export import (chrome_trace, validate_chrome_trace,
-                                  write_trace)
+    from repro.obs.export import (chrome_trace, dump_chrome_trace,
+                                  validate_chrome_trace)
     from repro.serving.cluster import EngineCluster
     from repro.serving.workload import (WorkloadConfig, make_workload,
                                         register_workload_prefixes,
@@ -332,7 +332,7 @@ def bench_trace(n_replicas: int = 2, n_sessions: int = 8, seed: int = 0,
         "same-seed traces are not byte-identical"
     assert meta["trace_export_valid"], errors
     if trace_out:
-        write_trace(t1, trace_out)
+        dump_chrome_trace(t1, trace_out)
         meta["trace_out"] = os.path.basename(trace_out)
     return meta
 

@@ -101,8 +101,7 @@ def main(argv=None):
                          "the same toolset share one engine prefix")
     ap.add_argument("--trace-out", default="",
                     help="write the unified pipeline+engine trace here "
-                         "(.jsonl = record-per-line, anything else = "
-                         "Chrome trace-event JSON for Perfetto)")
+                         "as Chrome trace-event JSON (open in Perfetto)")
     args = ap.parse_args(argv)
     if args.spec_decode and args.draft_k < 1:
         ap.error(f"--spec-decode needs --draft-k >= 1, "
@@ -232,8 +231,8 @@ def main(argv=None):
           "examples/train_planner.py fine-tunes the proxy into an "
           "accurate gate)")
     if tracer is not None:
-        from repro.obs.export import write_trace
-        write_trace(tracer, args.trace_out)
+        from repro.obs.export import dump_chrome_trace
+        dump_chrome_trace(tracer, args.trace_out)
         print(f"trace: {len(tracer.records)} records -> "
               f"{args.trace_out}")
     return {"sessions": len(results), "wall_s": dt, "pipeline": ps,

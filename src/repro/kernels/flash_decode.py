@@ -107,6 +107,7 @@ def flash_decode(q, k_cache, v_cache, kv_len, *, cap: float = 0.0,
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
         compiler_params=compiler_params(

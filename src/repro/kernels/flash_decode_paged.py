@@ -119,6 +119,7 @@ def flash_decode_paged(q, k_pages, v_pages, block_tab, kv_len, *,
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_decode_paged",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
         compiler_params=compiler_params(

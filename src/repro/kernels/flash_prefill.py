@@ -135,6 +135,7 @@ def flash_prefill(q, k, v, *, causal: bool = True, window: int = 0,
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_prefill",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hq, sq_pad, hd), q.dtype),
         compiler_params=compiler_params(

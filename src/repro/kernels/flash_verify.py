@@ -139,6 +139,7 @@ def flash_verify(q, k_cache, v_cache, kv_len, *, cap: float = 0.0,
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_verify",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G * W, hd), q.dtype),
         compiler_params=compiler_params(
@@ -191,6 +192,7 @@ def flash_verify_paged(q, k_pages, v_pages, block_tab, kv_len, *,
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_verify_paged",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G * W, hd), q.dtype),
         compiler_params=compiler_params(

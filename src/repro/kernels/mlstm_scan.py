@@ -117,6 +117,7 @@ def mlstm_scan(q, k, v, i_pre, f_pre, state=None, *, scale: float = 0.0,
     mspec = pl.BlockSpec((None, 1, 1), lambda bh, s: (bh, 0, 0))
     h, cf, nf, mf = pl.pallas_call(
         kernel,
+        name="mlstm_scan",
         grid=(BH, ns),
         in_specs=[seq, seq, seq, gate, gate, cspec, nspec, mspec],
         out_specs=[seq, cspec, nspec, mspec],

@@ -56,6 +56,7 @@ def moe_router_topk(logits, k: int, *, block_t: int = 256,
     kernel = functools.partial(_kernel, k=k)
     w, i = pl.pallas_call(
         kernel,
+        name="moe_router",
         grid=(nt,),
         in_specs=[pl.BlockSpec((block_t, E), lambda t: (t, 0))],
         out_specs=[pl.BlockSpec((block_t, k), lambda t: (t, 0)),

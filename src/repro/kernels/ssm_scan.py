@@ -76,6 +76,7 @@ def ssm_scan(dt, x, B_, C_, A, h0=None, *, block_d: int = 256,
     kernel = functools.partial(_kernel, block_s=block_s, ns=ns)
     y, h_last = pl.pallas_call(
         kernel,
+        name="ssm_scan",
         grid=(Bsz, nd, ns),
         in_specs=[
             pl.BlockSpec((None, block_s, block_d),

@@ -31,7 +31,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import init_params
 from repro.obs import Tracer
-from repro.obs.export import write_trace
+from repro.obs.export import dump_chrome_trace
 from repro.serving.cluster import ROUTER_POLICIES, EngineCluster
 from repro.serving.engine import InferenceEngine
 from repro.serving.sched import ADMISSION_POLICIES
@@ -108,7 +108,7 @@ def serve_cluster(cfg, params, args, spec_decode=None):
         print(f"  replica {r['replica']}: {r['admissions']} admissions, "
               f"hit {r['hit_ratio']:.2f}, util {r['utilization']:.2f}")
     if tracer is not None:
-        write_trace(tracer, args.trace_out)
+        dump_chrome_trace(tracer, args.trace_out)
         print(f"trace: {len(tracer.records)} records -> "
               f"{args.trace_out}")
 
@@ -199,14 +199,14 @@ def build_parser() -> argparse.ArgumentParser:
                          "requests retrieving the same toolset share "
                          "one cached prefill")
     ap.add_argument("--trace-out", default="",
-                    help="write the request-lifecycle trace here after "
-                         "the run: .jsonl = compact record-per-line, "
-                         "anything else = Chrome trace-event JSON "
-                         "(open in Perfetto / chrome://tracing). "
-                         "Cluster traces are tick-only and "
-                         "byte-identical across same-seed runs; the "
-                         "single-engine path injects time.time, so "
-                         "records also carry wall timestamps")
+                    help="write the request-lifecycle and step-phase "
+                         "trace here after the run, as Chrome "
+                         "trace-event JSON (open in Perfetto / "
+                         "chrome://tracing). Cluster traces are "
+                         "tick-only and byte-identical across "
+                         "same-seed runs; the single-engine path "
+                         "injects time.time, so its events are placed "
+                         "in wall time")
     return ap
 
 
@@ -363,12 +363,20 @@ def main(argv=None):
     ttft = [r.first_token_t - r.enqueue_t for r in done]
     print(f"p50 latency {sorted(lat)[len(lat)//2]*1000:.0f}ms | "
           f"p50 TTFT {sorted(ttft)[len(ttft)//2]*1000:.0f}ms")
+    # TTFT split where the engine observes it: enqueue -> admission,
+    # admission -> first token
+    queue_wait = engine.metrics.histogram("queue_wait_s").percentile(95)
+    prefill_wait = engine.metrics.histogram("prefill_wait_s").percentile(50)
+    print(f"p95 queue wait {queue_wait*1000:.0f}ms | "
+          f"p50 prefill wait {prefill_wait*1000:.0f}ms")
     if tracer is not None:
-        write_trace(tracer, args.trace_out)
+        dump_chrome_trace(tracer, args.trace_out)
         print(f"trace: {len(tracer.records)} records -> "
               f"{args.trace_out}")
     return {**st, "requests": len(prompts), "finished": len(done),
-            "wall_s": dt, "outputs": [r.output for r in sorted(
+            "wall_s": dt, "queue_wait_p95_s": queue_wait,
+            "prefill_wait_p50_s": prefill_wait,
+            "outputs": [r.output for r in sorted(
                 done, key=lambda r: r.request_id)]}
 
 

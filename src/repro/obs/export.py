@@ -1,4 +1,4 @@
-"""Trace export: Chrome trace-event JSON (Perfetto-loadable) + JSONL.
+"""Trace export: Chrome trace-event JSON (Perfetto-loadable).
 
 The Chrome format (the ``chrome://tracing`` / Perfetto "JSON trace
 event" schema) maps our tracks onto its process/thread axes:
@@ -9,7 +9,10 @@ event" schema) maps our tracks onto its process/thread axes:
     reserved ``queue`` / ``engine`` / ``kv`` lanes);
   * record ``tick``   -> ``ts`` in microseconds, spread by the
     within-tick ordinal so same-tick records keep their sequence order
-    on the timeline (1 tick = 1000 "us"; ticks are logical time).
+    on the timeline (1 tick = 1000 "us"; ticks are logical time) —
+    unless every record carries a ``wall`` stamp, in which case ``ts``
+    is the wall time in microseconds since the first record, so an
+    operator's trace shows real time.
 
 "M" metadata events name every process and thread. Serialization is
 deterministic — events in seq order, ``sort_keys`` JSON, fixed
@@ -75,23 +78,28 @@ def chrome_trace(records_or_tracer: Union[Tracer, Iterable[TraceRecord]]
         events.append({"ph": "M", "name": "thread_name",
                        "pid": pids[g], "tid": tid,
                        "args": {"name": _label_name("slot", lane)}})
+    on_wall = bool(records) and all(r.wall is not None for r in records)
+    wall0 = records[0].wall if on_wall else 0.0
     ordinal: Dict[int, int] = {}          # tick -> events seen
     for r in records:                     # seq order by construction
-        k = ordinal.get(r.tick, 0)
-        ordinal[r.tick] = k + 1
+        if on_wall:
+            ts = (r.wall - wall0) * 1e6
+        else:
+            k = ordinal.get(r.tick, 0)
+            ordinal[r.tick] = k + 1
+            ts = r.tick * TICK_US + min(k, TICK_US - 1)
         args = dict(r.args)
         args["seq"] = r.seq
         if r.wall is not None:
             args["wall"] = r.wall
         ev = {"ph": r.ph, "name": r.name, "pid": pids[r.group],
-              "tid": tids[(r.group, r.lane)],
-              "ts": r.tick * TICK_US + min(k, TICK_US - 1),
-              "args": args}
+              "tid": tids[(r.group, r.lane)], "ts": ts, "args": args}
         if r.ph == "i":
             ev["s"] = "t"                 # thread-scoped instant
         events.append(ev)
     return {"traceEvents": events, "displayTimeUnit": "ms",
-            "otherData": {"tick_us": TICK_US}}
+            "otherData": ({"wall_origin": wall0} if on_wall
+                          else {"tick_us": TICK_US})}
 
 
 def _dumps(obj) -> str:
@@ -99,41 +107,11 @@ def _dumps(obj) -> str:
 
 
 def dump_chrome_trace(tracer: Tracer, path) -> Path:
-    """Write the Perfetto-loadable JSON; returns the path."""
+    """Write the Perfetto-loadable JSON (``--trace-out``); returns the
+    path."""
     path = Path(path)
     path.write_text(_dumps(chrome_trace(tracer)) + "\n")
     return path
-
-
-def jsonl_lines(records_or_tracer: Union[Tracer, Iterable[TraceRecord]]
-                ) -> List[str]:
-    """One compact JSON object per record, seq order, key-sorted."""
-    records = (records_or_tracer.records
-               if isinstance(records_or_tracer, Tracer)
-               else tuple(records_or_tracer))
-    lines = []
-    for r in records:
-        d = {"seq": r.seq, "ph": r.ph, "name": r.name, "tick": r.tick,
-             "group": r.group, "lane": r.lane, "args": dict(r.args)}
-        if r.wall is not None:
-            d["wall"] = r.wall
-        lines.append(_dumps(d))
-    return lines
-
-
-def dump_jsonl(tracer: Tracer, path) -> Path:
-    path = Path(path)
-    path.write_text("\n".join(jsonl_lines(tracer)) + "\n")
-    return path
-
-
-def write_trace(tracer: Tracer, path) -> Path:
-    """``--trace-out`` dispatch: ``.jsonl`` writes the event log, any
-    other suffix the Chrome trace JSON."""
-    path = Path(path)
-    if path.suffix == ".jsonl":
-        return dump_jsonl(tracer, path)
-    return dump_chrome_trace(tracer, path)
 
 
 _PHASES = {"B", "E", "i", "M", "X"}

@@ -1,4 +1,5 @@
-"""Tick-stamped, monotonically-sequenced trace recording.
+"""Tick-stamped, monotonically-sequenced trace recording, with the
+engine's step phases also on the profiler's clock.
 
 A ``Tracer`` collects ``TraceRecord``s — begin/end span markers and
 instant events — stamped with the *engine tick* (``step_no`` / the
@@ -6,13 +7,21 @@ cluster's lockstep tick), never the wall clock. Each record also
 carries a process-global-free, tracer-local sequence number that is
 strictly increasing, so within-tick ordering is total and a trace is a
 pure function of the run: same seed ⇒ byte-identical records
-(tests/test_obs.py asserts this through both exporters).
+(tests/test_obs.py asserts this through the Chrome exporter).
 
 Wall time is opt-in: the engine's injected ``clock=`` is bound onto
 the tracer (``bind_clock``) only when a caller actually injects one
 (the live-serve launcher). Records then carry a ``wall`` field and the
-byte-identity guarantee is intentionally waived — determinism contracts
-stay with the tick stamps.
+byte-identity guarantee is intentionally waived — determinism
+contracts stay with the tick stamps.
+
+Two sinks, one API. ``span`` is for work that opens and closes inside
+one host call (an engine step and its phases): besides its B/E
+records it enters a ``jax.profiler.TraceAnnotation`` of the same name,
+so while a profile is recording the span sits on the profiler's host
+plane, on the same clock as the device's operations; with no profile
+running the annotation is a no-op. Spans that cross host calls (a
+request from admission to finish) stay ``begin``/``end`` records.
 
 Tracks: every record names a ``(group, lane)`` pair — the engine uses
 ``(replica_index, slot)`` with the reserved lanes ``"queue"`` /
@@ -20,15 +29,18 @@ Tracks: every record names a ``(group, lane)`` pair — the engine uses
 Chrome exporter (obs/export.py) maps groups to Perfetto processes and
 lanes to threads.
 
-``NullTracer`` is the zero-overhead default: every method is a no-op
-and ``enabled`` is False so hot loops can skip building event args
-entirely. Tracing on vs off never branches engine control flow, which
-is why tokens are bitwise identical either way.
+``NullTracer`` is the zero-overhead default: every method is a no-op,
+``span`` returns one shared inert context, and ``enabled`` is False so
+hot loops can skip building event args entirely. Tracing on vs off
+never branches engine control flow, which is why tokens are bitwise
+identical either way.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
+
+from jax.profiler import TraceAnnotation
 
 Label = Union[int, str]
 
@@ -46,6 +58,62 @@ class TraceRecord:
     lane: Label
     args: Tuple[Tuple[str, Any], ...] = ()
     wall: Optional[float] = None
+
+
+class _NullSpan:
+    """The inert context ``NullTracer.span`` returns (one shared
+    instance: entering it allocates nothing)."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set(self, **args) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    """A span that opens and closes within one host call: B/E records
+    on the tracer and a profiler annotation of the same name. ``set``
+    adds arguments known only at the close (they go on the E record
+    and onto the annotation)."""
+
+    __slots__ = ("_tracer", "_name", "_tick", "_group", "_lane",
+                 "_args", "_end_args", "_handle", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str, tick: int,
+                 group: Label, lane: Label, args: Dict[str, Any]):
+        self._tracer = tracer
+        self._name = name
+        self._tick = tick
+        self._group = group
+        self._lane = lane
+        self._args = args
+        self._end_args: Dict[str, Any] = {}
+
+    def __enter__(self) -> "_Span":
+        self._ann = TraceAnnotation(self._name, **self._args)
+        self._ann.__enter__()
+        self._handle = self._tracer.begin(
+            self._name, tick=self._tick, group=self._group,
+            lane=self._lane, **self._args)
+        return self
+
+    def set(self, **args) -> None:
+        self._end_args.update(args)
+
+    def __exit__(self, *exc) -> None:
+        self._tracer.end(self._handle, tick=self._tick, **self._end_args)
+        if self._end_args:
+            self._ann.set_metadata(**self._end_args)
+        self._ann.__exit__(*exc)
 
 
 class NullTracer:
@@ -69,6 +137,10 @@ class NullTracer:
 
     def end(self, handle: int, *, tick: int, **args) -> None:
         pass
+
+    def span(self, name: str, *, tick: int, group: Label = 0,
+             lane: Label = 0, **args) -> _NullSpan:
+        return _NULL_SPAN
 
     def open_spans(self) -> List[TraceRecord]:
         return []
@@ -130,6 +202,13 @@ class Tracer:
             raise ValueError(f"span {b.name!r} ends at tick {tick} "
                              f"before its begin tick {b.tick}")
         self._push("E", b.name, tick, b.group, b.lane, args)
+
+    def span(self, name: str, *, tick: int, group: Label = 0,
+             lane: Label = 0, **args) -> _Span:
+        """A context manager for a span that opens and closes within
+        one host call, at one tick: B/E records here, and a profiler
+        annotation of the same name (see the module docstring)."""
+        return _Span(self, name, tick, group, lane, args)
 
     # --------------------------------------------------- introspection ----
     @property

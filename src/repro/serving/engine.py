@@ -130,6 +130,7 @@ class Request:
     # (deadline passed while still queued — dropped, never admitted)
     finish_reason: Optional[str] = None
     enqueue_t: float = 0.0
+    admit_t: float = 0.0
     first_token_t: float = 0.0
     finish_t: float = 0.0
     # tick-based latency stamps (engine step numbers; the cluster's tick
@@ -378,16 +379,30 @@ class InferenceEngine:
         self._kv_peak_blocks = 0       # paged: peak pool blocks in use
         self._kv_peak_shared = 0       # paged: peak CoW-shared blocks
         self._kv_peak_slots = 0        # dense: peak busy slots
+        # wall-time waits, observed where they end: enqueue ->
+        # admission, and admission -> first token (injected clock)
+        self._queue_wait = self.metrics.histogram("queue_wait_s")
+        self._prefill_wait = self.metrics.histogram("prefill_wait_s")
 
+        # named functions, so their programs read jit_engine_<step> on
+        # a profiler trace
         be = self.backend
-        self._prefill = jax.jit(
-            lambda p, b: prefill(p, cfg, b, cache_len=cache_len,
-                                 backend=be))
-        self._decode = jax.jit(
-            lambda p, c, b: decode_step(p, cfg, c, b, backend=be))
-        self._extend = jax.jit(
-            lambda p, c, b, n: prefill_extend(p, cfg, c, b, n_valid=n,
-                                              backend=be))
+
+        def engine_prefill(p, b):
+            return prefill(p, cfg, b, cache_len=cache_len, backend=be)
+
+        def engine_decode(p, c, b):
+            return decode_step(p, cfg, c, b, backend=be)
+
+        def engine_extend(p, c, b, n):
+            return prefill_extend(p, cfg, c, b, n_valid=n, backend=be)
+
+        def engine_verify(p, c, b):
+            return verify_extend(p, cfg, c, b, backend=be)
+
+        self._prefill = jax.jit(engine_prefill)
+        self._decode = jax.jit(engine_decode)
+        self._extend = jax.jit(engine_extend)
         # multi-token cache extension: no ring buffers / cross-attention;
         # bucket-padded extends additionally require a stateless
         # (pure-attention) stack — recurrent state would step through pads
@@ -419,8 +434,7 @@ class InferenceEngine:
                                     cache_len=cache_len,
                                     backend=self.backend,
                                     metrics=self.metrics)
-            self._verify = jax.jit(
-                lambda p, c, b: verify_extend(p, cfg, c, b, backend=be))
+            self._verify = jax.jit(engine_verify)
 
     # ------------------------------------------------------------- API ----
     def add_request(self, prompt_text_or_ids, max_new_tokens: int = 32,
@@ -841,6 +855,15 @@ class InferenceEngine:
         self.stats["prefills"] += 1
         return logits, dict(cache1), None
 
+    def _note_admit(self, req: Request, slot: int):
+        """Count a fresh admission and stamp it, in ticks and in wall
+        time (the queue wait ends here)."""
+        self.stats["admissions"] += 1
+        req.admit_step = self.step_no
+        req.admit_t = self._clock()
+        self._queue_wait.observe(req.admit_t - req.enqueue_t)
+        self._trace_admit(req, slot)
+
     def _trace_admit(self, req: Request, slot: int,
                      resumed: bool = False):
         """Open the request's lifecycle span on its slot lane (admit →
@@ -868,6 +891,7 @@ class InferenceEngine:
         req.output.append(tok)
         req.first_token_t = self._clock()
         req.first_token_step = self.step_no
+        self._prefill_wait.observe(req.first_token_t - req.admit_t)
         if self.tracer.enabled:
             h = self._req_spans.get(req.request_id)
             lane = (self.tracer.lane_of(h)
@@ -923,9 +947,7 @@ class InferenceEngine:
                 self._finish_now(req, "cache_len")
                 finished.append(req)
                 continue
-            self.stats["admissions"] += 1
-            req.admit_step = self.step_no
-            self._trace_admit(req, slot)
+            self._note_admit(req, slot)
             if self.prefill_budget is not None:
                 free.popleft()
                 self._start_pending(slot, req, self._prefix_hit(req),
@@ -1048,9 +1070,7 @@ class InferenceEngine:
                     finished.append(req)
                     continue
             self.queue.pop()
-            self.stats["admissions"] += 1
-            req.admit_step = self.step_no
-            self._trace_admit(req, slot)
+            self._note_admit(req, slot)
             if self.prefill_budget is not None:
                 # chunked admission: take the blocks NOW (same math as
                 # the monolithic path below) so co-resident decodes
@@ -1141,35 +1161,41 @@ class InferenceEngine:
         spent = 0
         while spent < chunks and p.i < len(p.toks):
             rem = len(p.toks) - p.i
-            if p.cache is None:
-                # head: one B=1 prefill over the first chunk (or the
-                # whole short prompt)
-                n = min(rem, align)
-                head = jnp.asarray(p.toks[:n], jnp.int32)[None]
-                logits, cache = self._prefill(self.params,
-                                              {"tokens": head})
-                cache = dict(cache)
-                cache["pos"] = jnp.asarray(n, jnp.int32)
-                p.logits, p.cache = logits, cache
-                p.i = n
-            elif rem >= align:
-                chunk = jnp.asarray(p.toks[p.i:p.i + align],
-                                    jnp.int32)[None]
-                p.logits, p.cache = self._extend(
-                    self.params, p.cache, {"tokens": chunk}, align)
-                p.i += align
-            else:
-                # bucket-padded remainder — advance_cache_through's
-                # tail rule (cap the pad width at the cache end)
-                rest = p.toks[p.i:]
-                room = self.cache_len - int(p.cache["pos"])
-                if self._pad_extend and rem < room:
-                    width = min(1 << (rem - 1).bit_length(), room)
-                    rest = rest + [0] * (width - rem)
-                chunk = jnp.asarray(rest, jnp.int32)[None]
-                p.logits, p.cache = self._extend(
-                    self.params, p.cache, {"tokens": chunk}, rem)
-                p.i = len(p.toks)
+            n = min(rem, align)
+            kind = ("head" if p.cache is None
+                    else "extend" if n == align else "pad_extend")
+            with self.tracer.span("engine.prefill", tick=self.step_no,
+                                  group=self.trace_group, lane="engine",
+                                  request=p.req.request_id, tokens=n,
+                                  kind=kind):
+                if kind == "head":
+                    # one B=1 prefill over the first chunk (or the
+                    # whole short prompt)
+                    head = jnp.asarray(p.toks[:n], jnp.int32)[None]
+                    logits, cache = self._prefill(self.params,
+                                                  {"tokens": head})
+                    cache = dict(cache)
+                    cache["pos"] = jnp.asarray(n, jnp.int32)
+                    p.logits, p.cache = logits, cache
+                    p.i = n
+                elif kind == "extend":
+                    chunk = jnp.asarray(p.toks[p.i:p.i + align],
+                                        jnp.int32)[None]
+                    p.logits, p.cache = self._extend(
+                        self.params, p.cache, {"tokens": chunk}, align)
+                    p.i += align
+                else:
+                    # bucket-padded remainder — advance_cache_through's
+                    # tail rule (cap the pad width at the cache end)
+                    rest = p.toks[p.i:]
+                    room = self.cache_len - int(p.cache["pos"])
+                    if self._pad_extend and rem < room:
+                        width = min(1 << (rem - 1).bit_length(), room)
+                        rest = rest + [0] * (width - rem)
+                    chunk = jnp.asarray(rest, jnp.int32)[None]
+                    p.logits, p.cache = self._extend(
+                        self.params, p.cache, {"tokens": chunk}, rem)
+                    p.i = len(p.toks)
             spent += 1
         self.stats["prefill_chunks"] += spent
         if spent and self.tracer.enabled:
@@ -1228,7 +1254,12 @@ class InferenceEngine:
             allowance -= self._advance_pending(p, 1)
             if p.i >= len(p.toks):
                 self._pending_rr.popleft()
-                done = self._complete_pending(slot)
+                with self.tracer.span("engine.prefill", tick=self.step_no,
+                                      group=self.trace_group,
+                                      lane="engine",
+                                      request=p.req.request_id,
+                                      tokens=0, kind="complete"):
+                    done = self._complete_pending(slot)
                 if done is not None:
                     finished.append(done)
             else:
@@ -1248,12 +1279,26 @@ class InferenceEngine:
         drops). Paged mode additionally grows block tables before the
         decode/verify writes and may preempt-and-requeue under memory
         pressure (_ensure_room)."""
-        finished = self._step_once()
+        tr = self.tracer
+        with tr.span("engine.step", tick=self.step_no,
+                     group=self.trace_group, lane="engine",
+                     step_no=self.step_no, queued=len(self.queue)) as sp:
+            if tr.enabled:
+                admitted = self.stats["admissions"]
+                chunks = self.stats["prefill_chunks"]
+            finished, decoded = self._step_once()
+            if tr.enabled:
+                sp.set(admitted=self.stats["admissions"] - admitted,
+                       chunks=self.stats["prefill_chunks"] - chunks,
+                       decoded=decoded)
         self.step_no += 1
         return finished
 
-    def _step_once(self) -> List[Request]:
-        finished = self._admit()
+    def _step_once(self) -> Tuple[List[Request], int]:
+        """The body of ``step``; also returns how many slots decoded."""
+        with self.tracer.span("engine.admit", tick=self.step_no,
+                              group=self.trace_group, lane="engine"):
+            finished = self._admit()
         self._note_kv_peak()
         if self._pending:
             finished.extend(self._advance_pendings())
@@ -1264,19 +1309,25 @@ class InferenceEngine:
         active = [i for i, s in enumerate(self.slots)
                   if s is not None and i not in self._pending]
         if not active:
-            return finished
+            return finished, 0
         if stalled:
             self.stats["stall_ticks"] += 1
             if self.tracer.enabled:
                 self.tracer.event("stall", tick=self.step_no,
                                   group=self.trace_group, lane="engine",
                                   pending=len(self._pending))
-            return finished
+            return finished, 0
         if self.spec is not None:
             finished.extend(self._spec_step(active))
-            return finished
-        logits, self.cache = self._decode(self.params, self.cache,
-                                          {"tokens": self._last_tokens})
+            return finished, len(active)
+        # the dispatch only: the first token sync in engine.sample waits
+        # for the program (a wait here would stop the host dispatching
+        # the first slot's sampling while the program runs)
+        with self.tracer.span("engine.decode", tick=self.step_no,
+                              group=self.trace_group, lane="engine",
+                              slots=len(active)):
+            logits, self.cache = self._decode(
+                self.params, self.cache, {"tokens": self._last_tokens})
         self.stats["decode_steps"] += 1
         if self.tracer.enabled:
             self.tracer.event("decode", tick=self.step_no,
@@ -1287,26 +1338,32 @@ class InferenceEngine:
                 self.tables[i].n_tokens += 1
         # per-slot sampling: each slot draws its own engine-stream key,
         # unless the request carries a per-request seed (_request_key)
-        for i in active:
-            req = self.slots[i]
-            self.rng, ki = jax.random.split(self.rng)
-            tok = int(sample(logits[i:i + 1], self._request_key(req, ki),
-                             req.sampler)[0])
-            req.output.append(tok)
-            self.stats["tokens_generated"] += 1
-            self._last_tokens = self._last_tokens.at[i, 0].set(tok)
-            hit_cap = len(req.output) >= req.max_new_tokens
-            hit_len = int(self.cache["pos"][i]) + 1 >= self.cache_len - 1
-            if tok == SPECIALS["<eos>"] or hit_cap or hit_len:
-                self._finish_now(req, "eos" if tok == SPECIALS["<eos>"]
-                                 else "max_new_tokens" if hit_cap
-                                 else "cache_len")
-                finished.append(req)
-                self.slots[i] = None
-                self.cache["pos"] = self.cache["pos"].at[i].set(0)
-                if self.kv_mode == "paged":
-                    self._release_slot(i)
-        return finished
+        with self.tracer.span("engine.sample", tick=self.step_no,
+                              group=self.trace_group, lane="engine",
+                              slots=len(active)):
+            for i in active:
+                req = self.slots[i]
+                self.rng, ki = jax.random.split(self.rng)
+                tok = int(sample(logits[i:i + 1],
+                                 self._request_key(req, ki),
+                                 req.sampler)[0])
+                req.output.append(tok)
+                self.stats["tokens_generated"] += 1
+                self._last_tokens = self._last_tokens.at[i, 0].set(tok)
+                hit_cap = len(req.output) >= req.max_new_tokens
+                hit_len = (int(self.cache["pos"][i]) + 1
+                           >= self.cache_len - 1)
+                if tok == SPECIALS["<eos>"] or hit_cap or hit_len:
+                    self._finish_now(
+                        req, "eos" if tok == SPECIALS["<eos>"]
+                        else "max_new_tokens" if hit_cap
+                        else "cache_len")
+                    finished.append(req)
+                    self.slots[i] = None
+                    self.cache["pos"] = self.cache["pos"].at[i].set(0)
+                    if self.kv_mode == "paged":
+                        self._release_slot(i)
+        return finished, len(active)
 
     def _spec_step(self, active: List[int]) -> List[Request]:
         """One speculative round: K greedy draft steps, one target

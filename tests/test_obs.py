@@ -6,7 +6,8 @@ Four claims under test (DESIGN.md §Observability):
   * well-formedness — spans pair B/E per track, seq is strictly
     increasing, a drained run leaves no open spans;
   * byte identity — same seed ⇒ byte-identical serialized trace
-    (single engine AND a 2-replica cluster, through both exporters);
+    (single engine AND a 2-replica cluster, through the Chrome
+    exporter); a wall-stamped trace exports in wall time;
   * zero perturbation — tokens are bitwise identical with the tracer
     on vs the NullTracer default (tracing never branches control flow);
   * reset audit — ``engine.reset()``/``cluster.reset()`` zero the FULL
@@ -16,6 +17,7 @@ Four claims under test (DESIGN.md §Observability):
 import json
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -30,10 +32,9 @@ from repro.env.world import build_world
 from repro.models.model import init_params
 from repro.obs import (NULL_TRACER, MetricsRegistry, NullTracer,
                        StatsView, Tracer, percentile)
-from repro.obs.export import (chrome_trace, dump_chrome_trace,
-                              dump_jsonl, jsonl_lines,
-                              load_and_validate, validate_chrome_trace,
-                              write_trace)
+from repro.obs import tracer as tracer_mod
+from repro.obs.export import (TICK_US, chrome_trace, dump_chrome_trace,
+                              load_and_validate, validate_chrome_trace)
 from repro.serving.cluster import EngineCluster
 from repro.serving.engine import ENGINE_STAT_KEYS, InferenceEngine
 from repro.serving.pipeline import GeckOptPipeline, PipelineConfig
@@ -45,7 +46,9 @@ from repro.serving.workload import (WorkloadConfig, make_workload,
 EVENT_VOCAB = {
     "enqueue", "admit", "resume", "first_token", "finish", "preempt",
     "sla_expired", "kv_evict", "cow_fork", "prefill_chunk", "stall",
-    "decode", "spec_round", "request", "gate", "plan", "execute_wave"}
+    "decode", "spec_round", "request", "gate", "plan", "execute_wave",
+    "engine.step", "engine.admit", "engine.prefill", "engine.decode",
+    "engine.sample"}
 
 
 @pytest.fixture(scope="module")
@@ -142,6 +145,76 @@ def test_null_tracer_is_inert():
     assert not NULL_TRACER.enabled
 
 
+class _RecordingAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs enter, exit and
+    closing metadata."""
+    calls = []
+
+    def __init__(self, name, **args):
+        self.name = name
+        self.calls.append(("init", name, args))
+
+    def __enter__(self):
+        self.calls.append(("enter", self.name))
+        return self
+
+    def set_metadata(self, **args):
+        self.calls.append(("meta", self.name, args))
+
+    def __exit__(self, *exc):
+        self.calls.append(("exit", self.name))
+
+
+def test_tracer_span_writes_records_and_enters_annotation(monkeypatch):
+    _RecordingAnnotation.calls = []
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", _RecordingAnnotation)
+    t = Tracer(clock=iter([1.0, 2.5]).__next__)
+    with t.span("engine.step", tick=4, group=0, lane="engine",
+                step_no=4) as sp:
+        sp.set(decoded=3)
+    b, e = t.records
+    assert (b.ph, b.name, b.tick, b.lane, b.args, b.wall) == \
+        ("B", "engine.step", 4, "engine", (("step_no", 4),), 1.0)
+    assert (e.ph, e.name, e.tick, e.args, e.wall) == \
+        ("E", "engine.step", 4, (("decoded", 3),), 2.5)
+    assert t.open_spans() == []
+    # the annotation opens before the B record and closes after the E
+    assert _RecordingAnnotation.calls == [
+        ("init", "engine.step", {"step_no": 4}),
+        ("enter", "engine.step"),
+        ("meta", "engine.step", {"decoded": 3}),
+        ("exit", "engine.step")]
+
+
+def test_null_tracer_span_records_nothing(monkeypatch):
+    _RecordingAnnotation.calls = []
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", _RecordingAnnotation)
+    nt = NullTracer()
+    ctx = nt.span("engine.step", tick=0, lane="engine", step_no=0)
+    with ctx as sp:
+        sp.set(decoded=1)
+    # one shared inert context, no records, no annotation
+    assert nt.span("engine.decode", tick=1) is ctx
+    assert nt.records == () and _RecordingAnnotation.calls == []
+
+
+def test_chrome_export_places_wall_stamped_records_in_wall_time():
+    t = Tracer(clock=iter([10.0, 10.25, 10.5]).__next__)
+    t.event("enqueue", tick=0, lane="queue")
+    with t.span("engine.step", tick=0, lane="engine"):
+        pass
+    doc = chrome_trace(t)
+    evs = [e for e in doc["traceEvents"] if e["ph"] != "M"]
+    assert [e["ts"] for e in evs] == [0.0, 250000.0, 500000.0]
+    assert doc["otherData"] == {"wall_origin": 10.0}
+    assert validate_chrome_trace(doc) == []
+    # any record without a wall stamp keeps the whole trace on ticks
+    mixed = list(t.records) + [tracer_mod.TraceRecord(
+        seq=3, ph="i", name="stall", tick=1, group=0, lane="engine")]
+    assert [e["ts"] for e in chrome_trace(mixed)["traceEvents"]
+            if e["ph"] != "M"] == [0, 1, 2, TICK_US]
+
+
 # ------------------------------------------------------ metrics registry ----
 
 def test_registry_get_or_create_and_label_identity():
@@ -234,10 +307,83 @@ def test_traced_run_is_well_formed(planner, base_engine):
             < by_name["first_token"]
         assert len(spans) == 1 and spans[0].ph == "B"
         assert spans[0].lane in (0, 1)          # a slot lane
-    ends = [r for r in t.records if r.ph == "E"]
+    ends = [r for r in t.records if r.ph == "E" and r.name == "request"]
     assert len(ends) == 3
     assert all(dict(r.args)["reason"] in
                ("eos", "max_new_tokens") for r in ends)
+
+
+def test_engine_step_phases_nest_inside_the_step(planner, base_engine):
+    """One step that admits, prefills, decodes and samples: its phases
+    are spans on the engine track inside ``engine.step``, whose closing
+    args match the engine's counters."""
+    t = Tracer()
+    eng = make_engine(planner, base_engine, tracer=t, prefill_budget=2048)
+    eng.add_request("trace probe request number 0", max_new_tokens=4)
+    eng.add_request("trace probe request number 1", max_new_tokens=4)
+    before = dict(eng.stats)
+    t.clear()
+    eng.step()
+    recs = [r for r in t.records if r.name.startswith("engine.")]
+    assert all(r.lane == "engine" and r.group == 0 and r.tick == 0
+               for r in recs)
+    assert recs[0].name == recs[-1].name == "engine.step"
+    assert (recs[0].ph, recs[-1].ph) == ("B", "E")
+    inner = [(r.ph, r.name) for r in recs[1:-1]]
+    phases = [n for ph, n in inner if ph == "B"]
+    assert phases[0] == "engine.admit"
+    assert {"engine.prefill", "engine.decode", "engine.sample"} \
+        <= set(phases)
+    assert phases.index("engine.decode") + 1 \
+        == phases.index("engine.sample")
+    # the phases pair up in order: none straddles another
+    stack = []
+    for ph, n in inner:
+        if ph == "B":
+            stack.append(n)
+        else:
+            assert stack.pop() == n
+    assert stack == []
+    kinds = [dict(r.args)["kind"] for r in recs
+             if r.name == "engine.prefill" and r.ph == "B"]
+    assert kinds == ["head", "complete", "head", "complete"]
+    step_b, step_e = dict(recs[0].args), dict(recs[-1].args)
+    assert step_b == {"step_no": 0, "queued": 2}
+    assert step_e == {
+        "admitted": eng.stats["admissions"] - before["admissions"],
+        "chunks": eng.stats["prefill_chunks"] - before["prefill_chunks"],
+        "decoded": eng.stats["tokens_generated"]
+        - before["tokens_generated"]}
+    assert step_e["admitted"] == 2 and step_e["decoded"] == 2
+
+
+def test_engine_programs_carry_stable_names(planner, base_engine):
+    eng = make_engine(planner, base_engine)
+    batch = {"tokens": jnp.zeros((eng.max_batch, 1), jnp.int32)}
+    text = eng._decode.lower(eng.params, eng.cache, batch).as_text()
+    assert text.startswith("module @jit_engine_decode")
+    text = eng._prefill.lower(
+        eng.params, {"tokens": jnp.zeros((1, 8), jnp.int32)}).as_text()
+    assert text.startswith("module @jit_engine_prefill")
+
+
+def test_queue_and_prefill_waits_read_the_injected_clock(planner,
+                                                          base_engine):
+    # the clock's readings in turn: enqueue, admission, first token
+    reads = iter([100.0, 100.75, 101.0])
+    eng = make_engine(planner, base_engine, clock=lambda: next(reads),
+                      prefill_budget=1024)
+    eng.add_request("trace probe request number 0", max_new_tokens=4)
+    eng.step()
+    q = eng.metrics.histogram("queue_wait_s")
+    p = eng.metrics.histogram("prefill_wait_s")
+    assert q.values == [pytest.approx(0.75)]
+    assert p.values == [pytest.approx(0.25)]
+    (req,) = [r for r in eng.slots if r is not None]
+    assert (req.enqueue_t, req.admit_t, req.first_token_t) == \
+        (100.0, 100.75, 101.0)
+    eng.reset()
+    assert q.count == p.count == 0
 
 
 def test_tokens_bitwise_identical_tracer_on_vs_off(planner, base_engine):
@@ -257,18 +403,17 @@ def test_same_seed_engine_trace_byte_identical(planner, base_engine,
         eng = make_engine(planner, base_engine, tracer=t)
         serve_prompts(eng, n=3)
         paths.append(dump_chrome_trace(t, tmp_path / f"run{i}.json"))
-        # the JSONL exporter must agree with itself too
-        dump_jsonl(t, tmp_path / f"run{i}.jsonl")
     assert paths[0].read_bytes() == paths[1].read_bytes()
-    assert (tmp_path / "run0.jsonl").read_bytes() \
-        == (tmp_path / "run1.jsonl").read_bytes()
+    # tick-only: every event sits on the tick timeline
+    doc = json.loads(paths[0].read_text())
+    assert doc["otherData"] == {"tick_us": TICK_US}
 
 
 def test_perfetto_export_round_trip(planner, base_engine, tmp_path):
     t = Tracer()
     eng = make_engine(planner, base_engine, tracer=t)
     serve_prompts(eng, n=2)
-    path = write_trace(t, tmp_path / "trace.json")
+    path = dump_chrome_trace(t, tmp_path / "trace.json")
     doc, errors = load_and_validate(path)
     assert errors == []
     events = doc["traceEvents"]
@@ -279,11 +424,11 @@ def test_perfetto_export_round_trip(planner, base_engine, tmp_path):
     lanes = {e["args"]["name"] for e in meta
              if e["name"] == "thread_name"}
     assert "queue" in lanes and "engine" in lanes
-    # JSONL round-trips record-per-line
-    jl = write_trace(t, tmp_path / "trace.jsonl")
-    lines = [json.loads(ln) for ln in jl.read_text().splitlines()]
-    assert len(lines) == len(t.records)
-    assert lines[0]["name"] == "enqueue" and lines[0]["seq"] == 0
+    # every record round-trips as one event, in seq order
+    recs = [e for e in events if e["ph"] != "M"]
+    assert len(recs) == len(t.records)
+    assert [e["args"]["seq"] for e in recs] == list(range(len(recs)))
+    assert recs[0]["name"] == "enqueue"
 
 
 def test_validator_catches_malformed_documents():

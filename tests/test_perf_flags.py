@@ -164,9 +164,11 @@ def test_ssm_scan_chunk_invariance(chunk):
 
 # -------------------------------------------------------- parse_strategy ----
 
-def test_parse_strategy():
+def test_parse_strategy(monkeypatch):
     import os
-    os.environ.setdefault("XLA_FLAGS", "")
+    # importing dryrun appends a 512-device flag for its own command
+    # line: keep it out of any later JAX start in this process
+    monkeypatch.setenv("XLA_FLAGS", os.environ.get("XLA_FLAGS", ""))
     from repro.launch.dryrun import parse_strategy
     s = parse_strategy("prefill_seq_axis=model,fsdp=False")
     assert s.prefill_seq_axis == "model"

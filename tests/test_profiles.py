@@ -9,12 +9,14 @@ from repro.launch.profiles import BASE_PERF, PAIR_OVERRIDES, resolve
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
 @pytest.mark.parametrize("shape", list(INPUT_SHAPES))
-def test_resolve_parses_for_every_pair(arch, shape):
+def test_resolve_parses_for_every_pair(arch, shape, monkeypatch):
     perf, strategy = resolve(arch, shape)
     flags = PerfFlags().apply_overrides(perf)    # must not raise
     assert flags.attn_chunk_remat == "on"
     import os
-    os.environ.setdefault("XLA_FLAGS", "")
+    # importing dryrun appends a 512-device flag for its own command
+    # line: keep it out of any later JAX start in this process
+    monkeypatch.setenv("XLA_FLAGS", os.environ.get("XLA_FLAGS", ""))
     from repro.launch.dryrun import parse_strategy
     parse_strategy(strategy)                     # must not raise
 

@@ -76,6 +76,17 @@ def test_single_engine_run(capsys):
     assert [len(o) for o in st["outputs"]] == [2, 2]
 
 
+def test_single_engine_run_prints_its_waits(capsys):
+    # one slot: the second request waits in the queue for the first
+    st = main(["--smoke", "--requests", "2", "--max-new", "2",
+               "--max-batch", "1", "--cache-len", "128"])
+    out = capsys.readouterr().out
+    assert st["queue_wait_p95_s"] > 0 and st["prefill_wait_p50_s"] > 0
+    assert (f"p95 queue wait {st['queue_wait_p95_s'] * 1000:.0f}ms | "
+            f"p50 prefill wait {st['prefill_wait_p50_s'] * 1000:.0f}ms"
+            in out)
+
+
 def test_prompts_from_the_command_line(capsys):
     words = " ".join(f"w{i}" for i in range(128))   # 130 ids with specials
     st = main(["--smoke", "--backend", "pallas", "--max-new", "2",

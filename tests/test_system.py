@@ -50,9 +50,12 @@ def test_neural_intent_classifier_smoke():
     assert intent in INTENTS
 
 
-def test_dryrun_skip_logic():
+def test_dryrun_skip_logic(monkeypatch):
     from repro.common.config import INPUT_SHAPES
     from repro.configs import ARCH_IDS, get_config
+    # importing dryrun appends a 512-device flag for its own command
+    # line: keep it out of any later JAX start in this process
+    monkeypatch.setenv("XLA_FLAGS", os.environ.get("XLA_FLAGS", ""))
     from repro.launch.dryrun import skip_reason
 
     long = INPUT_SHAPES["long_500k"]

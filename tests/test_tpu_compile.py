@@ -140,3 +140,27 @@ def test_full_width_decode_step_compiles(one_chip, monkeypatch):
                                                backend="pallas"),
                    params, cache, tokens)
     assert "tpu_custom_call" in hlo
+
+
+def test_engine_programs_name_their_kernels(one_chip, monkeypatch):
+    """The engine's jitted steps lower as ``jit_engine_<step>`` modules
+    whose Pallas calls carry the kernel's own name, the names the
+    profiler's module and operation lines show on the chip."""
+    from repro.serving.engine import InferenceEngine
+    monkeypatch.setattr(KB, "_interpret", lambda: False)
+    cfg = get_config("planner-proxy-100m")
+    on_chip = lambda t: jax.tree.map(
+        lambda s: _shape(one_chip, s.shape, s.dtype), t)
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    eng = InferenceEngine(cfg, params, max_batch=2, cache_len=256,
+                          backend="pallas")
+    cache = on_chip(jax.eval_shape(lambda: eng.cache))
+    decode = eng._decode.lower(
+        params, cache, {"tokens": _shape(one_chip, (2, 1), jnp.int32)})
+    prefill = eng._prefill.lower(
+        params, {"tokens": _shape(one_chip, (1, 256), jnp.int32)})
+    assert decode.as_text().startswith("module @jit_engine_decode")
+    assert prefill.as_text().startswith("module @jit_engine_prefill")
+    assert "flash_decode" in decode.as_text()
+    assert "flash_prefill" in prefill.as_text()
