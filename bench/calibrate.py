@@ -8,9 +8,10 @@ load. One process, so the set-up's compiles are paid once.
 Each seed builds its weights and traffic, serves a short window, frees
 the program's state and runs the harness's comparison
 (bench/correct.py) once with the control in the program's place: its
-line gives the control's verdict (``correct``, which has to be false)
-and gap (``logit_gap``), and the program's own gap over the same
-sample (``program_gap``). The limit in the configuration file is set
+line names the model module whose reference judged it (``model``) and
+gives the control's verdict (``correct``, which has to be false) and
+gap (``logit_gap``), and the program's own gap over the same sample
+(``program_gap``). The limit in the configuration file is set
 between the program's largest reading and the control's smallest.
 """
 import argparse
@@ -38,7 +39,7 @@ def readings(cell, devices, seed: int, seconds: float, rehearse: bool):
     sys_.server.close()
     gc.collect()
     verdict = correct.judge(
-        sys_.weights, sys_.shape, log.reqs, seed,
+        cell.model, sys_.weights, sys_.shape, log.reqs, seed,
         sys_.conf["engine"]["cache_len"],
         float(sys_.conf["correct"]["logit_gap_limit"]), control=True)
     return verdict
@@ -52,12 +53,13 @@ def main(argv=None) -> int:
     found = prepare(args)
     if found is None:
         return 2
-    cell, devices, _, _ = found
+    cell, devices, _, root = found
+    model = os.path.relpath(cell.model.__file__, root)
     for k in range(args.seeds):
         v = readings(cell, devices, args.seed + k, args.seconds,
                      args.rehearse)
-        print("calibrate", json.dumps({"seed": args.seed + k, **v}),
-              flush=True)
+        print("calibrate", json.dumps({"seed": args.seed + k,
+                                       "model": model, **v}), flush=True)
     return 0
 
 

@@ -1,5 +1,6 @@
 """How ``correct`` is decided: what the timed path served, against the
-plain reference (bench/reference.py).
+plain reference of the cell's model module (``models/<model_type>.py``,
+its ``sequence_gaps``).
 
 Once the window has closed and the program's state is freed, a sample of
 the requests the run finished, drawn from the seed and always holding
@@ -21,8 +22,6 @@ from __future__ import annotations
 from typing import Dict, List, Tuple
 
 import numpy as np
-
-from bench import reference
 
 #: served tokens the sample has to reach (plus the longest request)
 SAMPLE_TOKENS = 320
@@ -62,9 +61,9 @@ def sample(reqs, seed: int) -> List:
     return picked
 
 
-def judge(weights, shape, reqs, seed: int, buf_len: int, limit: float,
-          control: bool = False) -> Dict:
-    """Run the reference over the sample; returns the verdict, the
+def judge(model, weights, shape, reqs, seed: int, buf_len: int,
+          limit: float, control: bool = False) -> Dict:
+    """Run ``model``'s reference over the sample; returns the verdict, the
     widest gap and the counts. With ``control`` the int8 control's
     tokens are judged in place of the served ones, and the program's
     own widest gap is returned beside them as ``program_gap``."""
@@ -74,8 +73,8 @@ def judge(weights, shape, reqs, seed: int, buf_len: int, limit: float,
     for r in picked:
         seq = r.prompt + r.output[:-1]
         tg = targets_of(r, reqs)
-        g, c = reference.sequence_gaps(weights, shape, seq, tg, buf_len,
-                                       control=control)
+        g, c = model.sequence_gaps(weights, shape, seq, tg, buf_len,
+                                   control=control)
         worst, ctrl = max(worst, g), max(ctrl, c)
         checked += len(tg)
     gap = ctrl if control else worst

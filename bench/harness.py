@@ -22,20 +22,23 @@ import os
 import shutil
 import sys
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from types import ModuleType
+from typing import Any, Dict, List, Optional
 
 import jax
 
 from bench import correct, devtrace, drive, spec, stats, traffic
-from bench.model import Shape, make_weights, model_config, smoke
 from bench.server import Server
 
 
 @dataclass
 class RunData:
-    """What a metric reader sees (``metrics/<name>.py``: ``read(run)``)."""
+    """What a metric reader sees (``metrics/<name>.py``: ``read(run)``).
+    ``model`` is the cell's ``models/<model_type>.py`` and ``shape`` its
+    ``Shape`` of the configuration."""
     log: drive.Log
-    shape: Shape
+    model: ModuleType
+    shape: Any
     peaks: Optional[Dict[str, float]]
     n_chips: int
     setup_s: float
@@ -73,7 +76,7 @@ class System:
     test, the compile clock."""
     conf: Dict
     mix: Dict
-    shape: Shape
+    shape: Any
     weights: object
     server: Server
     chips: List
@@ -87,18 +90,19 @@ def build(cell: spec.Cell, *, seed: int, devices,
     from repro.common import perf
     from repro.serving.tokenizer import SPECIALS
 
-    conf = smoke(cell.config) if rehearse else cell.config
-    mix = smoke(cell.mix) if rehearse else cell.mix
+    conf = spec.smoke(cell.config) if rehearse else cell.config
+    mix = spec.smoke(cell.mix) if rehearse else cell.mix
     if "attn_chunk" in conf:
         # rehearsals scale the program's prefill chunk with the model
         perf.set_flags(dataclasses.replace(perf.get_flags(),
                                            attn_chunk=conf["attn_chunk"]))
     compiles = drive.CompileClock()
     jax.monitoring.register_event_duration_secs_listener(compiles)
-    shape = Shape.of(conf)
+    model = cell.model
+    shape = model.Shape.of(conf)
     chips = list(devices[:cell.chips])
-    weights = make_weights(shape, seed, eos_id=SPECIALS["<eos>"])
-    server = Server(model_config(conf), weights, conf["engine"],
+    weights = model.make_weights(shape, seed, eos_id=SPECIALS["<eos>"])
+    server = Server(model.model_config(conf), weights, conf["engine"],
                     conf.get("replicas", 1), chips, seed)
     return System(conf, mix, shape, weights, server, chips, compiles)
 
@@ -177,7 +181,8 @@ def run(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
             say(f"chip {d}: busy {reduced.busy_ns[d] / 1e9:.6f} s of "
                 f"{reduced.window_ns / 1e9:.6f} s, idle {100 * idle:.4f}%")
 
-    data = RunData(log, shape, peaks, len(chips), setup_s, reduced)
+    data = RunData(log, cell.model, shape, peaks, len(chips), setup_s,
+                   reduced)
     wanted = cell.per_layer if trace else cell.end_to_end
     metrics = {}
     for m in wanted:
@@ -189,7 +194,7 @@ def run(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
     server.close()
     del server, sys_
     gc.collect()
-    verdict = correct.judge(weights, shape, log.reqs, seed,
+    verdict = correct.judge(cell.model, weights, shape, log.reqs, seed,
                             conf["engine"]["cache_len"],
                             float(conf["correct"]["logit_gap_limit"]))
     say(f"compared: {verdict['requests_checked']} requests, "

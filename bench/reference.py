@@ -1,36 +1,35 @@
-"""The plain reference: the decoder's forward pass in float32 at the
-highest matmul precision, written from the published description
-(pre-norm RMSNorm decoder, rotary embeddings on the two halves of each
-head, grouped-query causal attention, SiLU-gated MLP, QKV bias where the
-configuration has it). It imports nothing of the program and takes
-nothing the program made: only the benchmark's own weights and token
-ids.
+"""What every model module's plain reference shares (``models/*.py``
+may import these): the key its weights are drawn from, float32 matmuls
+at the highest precision with their int8 control, RMSNorm, rotary
+embeddings, and the loop that runs a reference over one sequence in
+blocks of ``CHUNK`` positions and reads the gaps. None of it imports
+the program or takes anything the program made.
 
-It runs in blocks of ``CHUNK`` positions against its own K/V buffers,
-layer by layer inside one compiled program per configuration, so a
-sequence of any length up to the buffer fits beside the weights.
-
-``precision="int8"`` is the control: the same pass with every weight
-matrix and its input activations quantized to int8 (symmetric, per
-output channel and per row), the step below the configuration's
-bfloat16. The comparison has to fail it.
+``precision="int8"`` is the control: every weight matrix and its input
+activations quantized to int8 (symmetric, per output channel and per
+row), the step below the configurations' bfloat16. The comparison has
+to fail it.
 
 RMSNorm scales are stored as offsets from one (the weights' layout):
 the weight a published checkpoint would hold is ``1 + scale``.
 """
 from __future__ import annotations
 
-import functools
-from typing import List, Tuple
+from typing import Callable, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.model import Shape
-
 CHUNK = 512
 HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def weight_key(seed: int) -> jax.Array:
+    """A PRNG key for any whole-number seed (the driver's exceed 32
+    bits)."""
+    word = int(np.random.default_rng([seed, 0x5EED]).integers(0, 2**31 - 1))
+    return jax.random.PRNGKey(word)
 
 
 def _q8(a, axis):
@@ -40,8 +39,8 @@ def _q8(a, axis):
     return jnp.round(a / scale).astype(jnp.int8), scale
 
 
-def _matmul(x, w, precision):
-    """x (n, k) f32 @ w (k, m) f32."""
+def matmul(x, w, precision):
+    """x (n, k) f32 @ w (k, m) f32, in float32 or as the int8 control."""
     if precision == "float32":
         return jnp.matmul(x, w, precision=HIGHEST)
     xq, xs = _q8(x, 1)
@@ -51,72 +50,20 @@ def _matmul(x, w, precision):
     return acc.astype(jnp.float32) * xs * ws
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
     return x * (1.0 + scale.astype(jnp.float32))
 
 
-def _rope(x, pos, theta):
-    """x (h, n, hd) at positions pos (n,)."""
+def rope(x, pos, theta):
+    """x (h, n, hd) at positions pos (n,), rotating the two halves of
+    each head."""
     hd = x.shape[-1]
     freqs = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
     ang = pos.astype(jnp.float32)[:, None] * freqs
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-@functools.partial(jax.jit, static_argnames=("shape", "precision"),
-                   donate_argnames=("kbuf", "vbuf"))
-def forward_chunk(weights, kbuf, vbuf, tokens, offset, *, shape: Shape,
-                  precision: str):
-    """Positions [offset, offset + CHUNK) of one sequence: writes their
-    K/V into the buffers (L, Hkv, S, hd) and returns their logits
-    (CHUNK, V) in float32."""
-    s = shape
-    f32 = lambda a: a.astype(jnp.float32)
-    mm = functools.partial(_matmul, precision=precision)
-    n = tokens.shape[0]
-    pos = offset + jnp.arange(n)
-    x = f32(jnp.take(weights["embed"], tokens, axis=0))
-    G = s.n_heads // s.n_kv_heads
-    S = kbuf.shape[2]
-    visible = jnp.arange(S)[None, :] <= pos[:, None]          # (n, S)
-
-    def layer(x, xs):
-        p, kb, vb = xs
-        a = p["attn"]
-        h = _rms(x, p["norm1"]["scale"], s.norm_eps)
-        q, k, v = mm(h, f32(a["wq"])), mm(h, f32(a["wk"])), \
-            mm(h, f32(a["wv"]))
-        if s.qkv_bias:
-            q, k, v = q + f32(a["bq"]), k + f32(a["bk"]), v + f32(a["bv"])
-        heads = lambda t, m: t.reshape(n, m, s.d_head).transpose(1, 0, 2)
-        q = _rope(heads(q, s.n_heads), pos, s.rope_theta)
-        k = _rope(heads(k, s.n_kv_heads), pos, s.rope_theta)
-        v = heads(v, s.n_kv_heads)
-        kb = jax.lax.dynamic_update_slice(kb, k, (0, offset, 0))
-        vb = jax.lax.dynamic_update_slice(vb, v, (0, offset, 0))
-        kg = jnp.repeat(kb, G, axis=0)                         # (H, S, hd)
-        vg = jnp.repeat(vb, G, axis=0)
-        sc = jnp.einsum("hnd,hsd->hns", q, kg, precision=HIGHEST) \
-            / np.sqrt(s.d_head)
-        sc = jnp.where(visible[None], sc, -jnp.inf)
-        o = jnp.einsum("hns,hsd->hnd", jax.nn.softmax(sc, axis=-1), vg,
-                       precision=HIGHEST)
-        o = o.transpose(1, 0, 2).reshape(n, s.n_heads * s.d_head)
-        x = x + mm(o, f32(a["wo"]))
-        h = _rms(x, p["norm2"]["scale"], s.norm_eps)
-        m = p["mlp"]
-        g = mm(h, f32(m["w_gate"]))
-        x = x + mm(jax.nn.silu(g) * mm(h, f32(m["w_up"])), f32(m["w_down"]))
-        return x, (kb, vb)
-
-    layers = weights["segments"][0][0]
-    x, (kbuf, vbuf) = jax.lax.scan(layer, x, (layers, kbuf, vbuf))
-    x = _rms(x, weights["final_norm"]["scale"], s.norm_eps)
-    head = (weights["embed"].T if s.tied else weights["lm_head"])
-    return mm(x, f32(head)), kbuf, vbuf
 
 
 @jax.jit
@@ -133,23 +80,25 @@ def _gaps(ref_logits, served, valid, ctrl_logits, in_seq):
     return jnp.max(gap), jnp.max(ctrl)
 
 
-def sequence_gaps(weights, shape: Shape, seq: List[int],
-                  targets: List[Tuple[int, int]], buf_len: int,
-                  control: bool = False) -> Tuple[float, float]:
+def chunked_gaps(step: Callable, buffers: Callable, seq: List[int],
+                 targets: List[Tuple[int, int]], buf_len: int,
+                 control: bool = False) -> Tuple[float, float]:
     """Widest gap of the served tokens ``targets`` -- (position whose
     logits predict it, token) pairs -- of one sequence, and, with
     ``control``, the widest gap of the int8 control's own choices at
     every position of the sequence (else 0): the control need not
-    decode."""
+    decode.
+
+    ``step(bufs, ids, offset, precision)`` runs a model's reference over
+    positions [offset, offset + CHUNK) and returns their logits (CHUNK,
+    V) in float32 with its caches updated; ``buffers()`` gives a fresh
+    set of those caches for ``buf_len`` positions."""
     if len(seq) > buf_len:
         raise ValueError(f"sequence of {len(seq)} > buffer {buf_len}")
     by_pos = dict(targets)
-    s = shape
-    zeros = lambda: jnp.zeros((s.layers, s.n_kv_heads, buf_len, s.d_head),
-                              jnp.float32)
-    bufs = {"float32": [zeros(), zeros()]}
+    bufs = {"float32": buffers()}
     if control:
-        bufs["int8"] = [zeros(), zeros()]
+        bufs["int8"] = buffers()
     last = len(seq) - 1 if control else max(by_pos)
     worst = ctrl_worst = 0.0
     for off in range(0, last + 1, CHUNK):
@@ -162,11 +111,9 @@ def sequence_gaps(weights, shape: Shape, seq: List[int],
             if off + i in by_pos:
                 served[i], valid[i] = by_pos[off + i], True
         logits = {}
-        for prec, (kb, vb) in bufs.items():
-            logits[prec], kb, vb = forward_chunk(
-                weights, kb, vb, jnp.asarray(ids), off, shape=s,
-                precision=prec)
-            bufs[prec] = [kb, vb]
+        for prec in bufs:
+            logits[prec], bufs[prec] = step(bufs[prec], jnp.asarray(ids),
+                                            off, prec)
         if not (valid.any() or control):
             continue
         in_seq = np.arange(CHUNK) < len(seq) - off
